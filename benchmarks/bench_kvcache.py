@@ -151,14 +151,17 @@ def run(quick: bool = False) -> dict:
     kernel_identical = bool(
         (np.asarray(got).view(np.uint16) ==
          np.asarray(want).view(np.uint16)).all())
+    from repro.kernels.backend import interpret_mode
+
     micro = {
-        "interpret": True,
+        "interpret": interpret_mode(),
         "append_us": us_append,
         "stream_attention_us": us_stream,
         "dense_oracle_attention_us": us_dense,
         "kernel_bit_identical": kernel_identical,
     }
-    print(f"kvcache/append,{us_append:.1f},interpret=True", flush=True)
+    print(f"kvcache/append,{us_append:.1f},interpret={interpret_mode()}",
+          flush=True)
     print(f"kvcache/stream_attention,{us_stream:.1f},"
           f"dense_oracle_us={us_dense:.1f};identical={kernel_identical}",
           flush=True)

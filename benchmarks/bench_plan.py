@@ -132,12 +132,12 @@ def run_exec(quick: bool = False) -> dict:
     # decode: per-unit kernels vs one fused kernel (both interpret mode)
     n_units = decode_plan(lay).n_units
     t0 = time.perf_counter()
-    legacy_out = decode_layout(lay, buf, fused=False, interpret=True)
+    legacy_out = decode_layout(lay, buf, fused=False)
     decode_legacy_us = (time.perf_counter() - t0) * 1e6
-    fused_out = decode_layout(lay, buf, fused=True, interpret=True,
+    fused_out = decode_layout(lay, buf, fused=True,
                               program=prog)              # trace + check
     decode_us = _timeit_min(
-        lambda: decode_layout(lay, buf, fused=True, interpret=True,
+        lambda: decode_layout(lay, buf, fused=True,
                               program=prog),
         repeats=3, warmup=0)
     decode_ok = all(

@@ -76,7 +76,7 @@ def _single_stream_oracle(cfg, tree, model, req):
         tok = req.prompt[pos] if pos < len(req.prompt) \
             else generated[-1]
         logits, state = packed_decode_step(
-            cfg, tree, state, jnp.asarray([tok], jnp.int32), interpret=True)
+            cfg, tree, state, jnp.asarray([tok], jnp.int32))
         pos += 1
         if pos >= len(req.prompt):
             generated.append(int(np.asarray(logits[0]).argmax()))

@@ -127,15 +127,14 @@ def run(quick: bool = False) -> dict:
         for name, (k_, n_) in shapes.items():
             t = tabs[name]
             outs[name] = stream_matmul(
-                xs[name], sw, t.w_tab, t.s_tab, bits=bits, group_size=g,
-                block_k=_bk(k_), block_n=_bn(n_), interpret=True)
+                xs[name], sw, t, block_k=_bk(k_), block_n=_bn(n_))
         jax.block_until_ready(list(outs.values()))
         return outs
 
     rebias = 128 - (1 << (bits - 1))
 
     def two_pass_token():
-        dec = decode_layout_fused(lay, buf, program=prog, interpret=True)
+        dec = decode_layout_fused(lay, buf, program=prog)
         outs = {}
         for name, (k_, n_) in shapes.items():
             codes = (jnp.asarray(dec[name])[:k_ * n_].reshape(k_, n_)
@@ -146,7 +145,7 @@ def run(quick: bool = False) -> dict:
             pw = pack_codes_u32(codes, 8)
             outs[name] = packed_matmul(
                 xs[name], pw, scales, bits=8, group_size=g,
-                block_k=_bk(k_), block_n=_bn(n_), interpret=True)
+                block_k=_bk(k_), block_n=_bn(n_))
         jax.block_until_ready(list(outs.values()))
         return outs
 

@@ -173,7 +173,7 @@ def bench_decode_kernel() -> None:
                                ("b", 32, 128, 8)])
     pl = api.plan(p)
     buf = pl.pack(api.random_codes(p))
-    us_k = _timeit(lambda: pl.decode(buf, backend="pallas", interpret=True),
+    us_k = _timeit(lambda: pl.decode(buf, backend="pallas"),
                    repeats=2)
     us_r = _timeit(lambda: pl.decode(buf, backend="numpy"), repeats=2)
     _row("decode_kernel/pallas_interpret", us_k, f"oracle_us={us_r:.1f}")
@@ -196,13 +196,12 @@ def bench_packed_matmul() -> None:
 
         def run(bits=bits, pw=pw, qt=qt, x=x):
             packed_matmul(x, pw, qt.scales, bits=bits, group_size=128,
-                          block_m=64, block_k=256,
-                          interpret=True).block_until_ready()
+                          block_m=64, block_k=256).block_until_ready()
 
         us = _timeit(run, repeats=2)
         ref = packed_matmul_ref(x, pw, qt.scales, bits=bits, group_size=128)
         got = packed_matmul(x, pw, qt.scales, bits=bits, group_size=128,
-                            block_m=64, block_k=256, interpret=True)
+                            block_m=64, block_k=256)
         err = float(jnp.abs(got - ref).max())
         packed_bytes = pw.size * 4 + qt.scales.size * 2
         dense_bytes = k * n * 2
@@ -228,8 +227,7 @@ def bench_ssd_scan_kernel() -> None:
         jax.random.normal(ks[3], (b, t, h), jnp.float32))
 
     def run_kernel():
-        ssd_scan(q, k, v, logw, chunk=128,
-                 interpret=True).block_until_ready()
+        ssd_scan(q, k, v, logw, chunk=128).block_until_ready()
 
     def run_ref():
         recurrent_scan(q, k, v, logw[..., None],
@@ -237,7 +235,7 @@ def bench_ssd_scan_kernel() -> None:
 
     us_k = _timeit(run_kernel, repeats=2)
     us_r = _timeit(run_ref, repeats=2)
-    got = ssd_scan(q, k, v, logw, chunk=128, interpret=True)
+    got = ssd_scan(q, k, v, logw, chunk=128)
     want, _ = recurrent_scan(q, k, v, logw[..., None], rwkv_mode=False)
     err = float(jnp.abs(got - want).max())
     # HBM state traffic per chunk: pure-JAX round-trips the f32 state

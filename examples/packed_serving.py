@@ -18,6 +18,7 @@ import numpy as np
 
 from repro import api
 from repro.configs import get_config
+from repro.kernels.backend import interpret_mode
 from repro.models.model import Model
 from repro.models.quantized import bytes_per_token_report, packed_decode_step
 from repro.quant import QuantSpec
@@ -66,16 +67,16 @@ def main() -> None:
     outs = [[] for _ in range(args.batch)]
     t0 = time.perf_counter()
     for _ in range(args.new_tokens):
-        logits, state = packed_decode_step(cfg, pp, state, toks,
-                                           interpret=True)
+        logits, state = packed_decode_step(cfg, pp, state, toks)
         toks = jnp.argmax(logits, -1).astype(jnp.int32)
         for i in range(args.batch):
             outs[i].append(int(toks[i]))
     dt = time.perf_counter() - t0
     for i, o in enumerate(outs):
         print(f"request {i}: {o}")
+    mode = "interpret-mode" if interpret_mode() else "Mosaic"
     print(f"\n{args.batch * args.new_tokens} tokens in {dt:.1f}s "
-          f"(interpret-mode Pallas on CPU; TPU is the lowering target)")
+          f"({mode} Pallas on {jax.default_backend()})")
 
     print("\n=== Packed checkpoint (the HBM stream is the checkpoint) ===")
     import pathlib
@@ -98,7 +99,7 @@ def main() -> None:
     state2 = model.init_decode_state(args.batch, max_seq=64)
     t = jnp.asarray(rng.integers(0, cfg.vocab_size, args.batch), jnp.int32)
     dlog, _ = jax.jit(model.decode_step)(params, state2, t, None)
-    qlog, _ = packed_decode_step(cfg, pp, state2, t, interpret=True)
+    qlog, _ = packed_decode_step(cfg, pp, state2, t)
     agree = float((np.argmax(np.asarray(dlog), -1)
                    == np.argmax(np.asarray(qlog), -1)).mean())
     print(f"top-1 agreement packed vs dense: {agree:.0%}  [OK]")

@@ -22,8 +22,9 @@ Two registries make the pipeline pluggable:
   ``"hls_padded"``.  Sweeps and comparisons iterate the registry
   (:func:`compare`) instead of importing one function per family.
 * **backends** (:data:`BACKENDS`) execute a plan — ``"numpy"`` is the
-  reference bit-gatherer, ``"pallas"`` the TPU kernel path (interpret
-  mode off-TPU), ``"c"`` emits the paper's Listing 1/2 HLS source.
+  reference bit-gatherer, ``"pallas"`` the TPU kernel path (Pallas
+  interpret mode on a non-TPU backend, :mod:`repro.kernels.backend`),
+  ``"c"`` emits the paper's Listing 1/2 HLS source.
   ``plan.decode`` normalizes every backend's output to uint64 numpy
   arrays, so cross-backend equivalence is plain ``np.array_equal``.
 
@@ -162,15 +163,14 @@ def _decode_numpy(pl: "Plan", buf: np.ndarray, *,
 
 
 def _decode_pallas(pl: "Plan", buf: np.ndarray, *,
-                   interpret: bool = True,
                    fused: bool = True) -> dict[str, np.ndarray]:
     from .kernels.ops import decode_layout  # lazy: pulls in JAX
 
     if fused:
-        return _as_u64(decode_layout(pl.layout, buf, interpret=interpret,
-                                     fused=True, program=pl.exec_program))
-    return _as_u64(decode_layout(pl.layout, buf, interpret=interpret,
-                                 fused=False, plan=pl.decode_plan))
+        return _as_u64(decode_layout(pl.layout, buf, fused=True,
+                                     program=pl.exec_program))
+    return _as_u64(decode_layout(pl.layout, buf, fused=False,
+                                 plan=pl.decode_plan))
 
 
 def _emit_c(pl: "Plan", *, artifact: str = "decode",
@@ -375,7 +375,7 @@ class Plan:
                       shape: tuple[int, int], *, scales: int | str,
                       group_size: int,
                       elem_widths: tuple[int, ...] | None = None,
-                      interpret: bool = True, **block_kw):
+                      **block_kw):
         """``x @ dequant(weights)`` straight out of the packed stream.
 
         The stream-direct exec surface: no dense intermediate ever
@@ -385,9 +385,10 @@ class Plan:
         ``(c_max, m/8)`` uint8 buffer (or a precomputed uint32 stream
         from :func:`repro.kernels.stream_matmul.stream_words`).
         """
-        import jax.numpy as jnp  # lazy: pulls in JAX
-
-        from .kernels.stream_matmul import stream_matmul, stream_words
+        from .kernels.stream_matmul import (  # lazy: pulls in JAX
+            stream_matmul,
+            stream_words,
+        )
 
         tabs = self.stream_tables(weights, shape, scales=scales,
                                   group_size=group_size,
@@ -397,10 +398,7 @@ class Plan:
             prog = self.exec_program if elem_widths is None \
                 else lower_exec(self.layout, elem_widths=elem_widths)
             buf = stream_words(prog, np.asarray(buf))
-        return stream_matmul(x, buf, jnp.asarray(tabs.w_tab),
-                             jnp.asarray(tabs.s_tab), bits=tabs.bits,
-                             group_size=group_size, interpret=interpret,
-                             **block_kw)
+        return stream_matmul(x, buf, tabs, **block_kw)
 
     # -- conveniences ---------------------------------------------------
     def validate(self) -> "Plan":
@@ -572,8 +570,8 @@ class LayerStackPlan:
             name, shape, scales=sname, group_size=group_size,
             elem_widths=ew)
 
-    def matmul_direct(self, x, buf, name: str, shape: tuple[int, int], *,
-                      interpret: bool = True, **block_kw):
+    def matmul_direct(self, x, buf, name: str, shape: tuple[int, int],
+                      **block_kw):
         """Stream-direct ``x @ dequant(name)`` against one layer's buffer.
 
         ``buf`` is that layer's packed stream (uint8 rows or a
@@ -585,8 +583,7 @@ class LayerStackPlan:
         ew = tuple(b.width_bits for b in self.bundle)
         return self.plans[0].matmul_direct(
             x, buf, name, shape, scales=f"{name}_scales",
-            group_size=group_size, elem_widths=ew, interpret=interpret,
-            **block_kw)
+            group_size=group_size, elem_widths=ew, **block_kw)
 
 
 def plan_layer_stack(cfg, qspec, *, m: int = 4096,

@@ -234,6 +234,11 @@ class StreamTables:
     group_size: int
     w_tab: np.ndarray            # (K, N) uint32
     s_tab: np.ndarray            # (K // group_size, N) uint32
+    #: the program the offsets index; kernels memoize their forms of
+    #: these tables in its ``jit_cache`` under ``("stream", key, ...)``
+    program: ExecProgram = dataclasses.field(repr=False)
+    #: (weight array, scale array, K, N): names these tables in the program
+    key: tuple[int, int, int, int] = dataclasses.field(repr=False)
 
 
 def stream_matmul_tables(layout: Layout, weights: int | str,
@@ -289,7 +294,8 @@ def stream_matmul_tables(layout: Layout, weights: int | str,
     w_tab = prog.stream_bit_offsets(wi)[:k * n].reshape(k, n)
     s_tab = prog.stream_bit_offsets(si)[:g * n].reshape(g, n)
     return StreamTables(bits=bits, group_size=group_size,
-                        w_tab=w_tab, s_tab=s_tab)
+                        w_tab=w_tab, s_tab=s_tab, program=prog,
+                        key=(wi, si, k, n))
 
 
 # ----------------------------------------------------------------------

@@ -986,10 +986,10 @@ def _pool_schedule(probs: list[LayoutProblem], mode: str,
     chunks = [probs[i:i + per] for i in range(0, len(probs), per)]
     payloads = [([p.to_json() for p in ch], mode, fill_residual)
                 for ch in chunks]
-    methods = multiprocessing.get_all_start_methods()
-    method = "fork" if "fork" in methods else "spawn"
+    # spawn, never fork: a forked child of a process that has touched
+    # JAX would inherit its accelerator handles (one process per chip)
     try:
-        ctx = multiprocessing.get_context(method)
+        ctx = multiprocessing.get_context("spawn")
         with ctx.Pool(processes=min(workers, len(chunks))) as pool:
             results = pool.map(_schedule_worker, payloads)
     except Exception as e:  # sandboxed / fork-less hosts: run serially

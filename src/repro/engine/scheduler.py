@@ -186,8 +186,7 @@ class PackedAdapter:
     a dense oracle first — the bit-identity verification path).
     """
 
-    def __init__(self, cfg, tree, *, weights: str = "auto",
-                 interpret: bool = True, uploader=None,
+    def __init__(self, cfg, tree, *, weights: str = "auto", uploader=None,
                  kv: str = "dense", kv_attention: str = "stream",
                  kv_bits: int | None = None, page_tokens: int = 8,
                  kv_m: int = 512) -> None:
@@ -202,7 +201,6 @@ class PackedAdapter:
         self.cfg = cfg
         self.tree = tree
         self.weights = weights
-        self.interpret = interpret
         self.uploader = uploader
         self.kv = kv
         self.kv_attention = kv_attention
@@ -234,7 +232,7 @@ class PackedAdapter:
 
         logits, state = packed_decode_step(
             self.cfg, self.tree, state, jnp.asarray(tokens, jnp.int32),
-            interpret=self.interpret, weights=self.weights,
+            weights=self.weights,
             slot_ids=jnp.asarray(list(active), jnp.int32),
             stream_source=self.uploader,
             kv=self.kv, kv_attention=self.kv_attention)

@@ -28,6 +28,7 @@ boundary (never a row boundary) — a two-word funnel shift recovers it.
 from __future__ import annotations
 
 import functools
+import re
 import warnings
 
 import jax
@@ -39,6 +40,8 @@ from repro.core.exec_plan import _TAB_WIDTH_SHIFT, ExecProgram, lower_exec
 from repro.core.layout import Layout
 from repro.core.util import round_up as _round_up
 
+from . import backend
+
 
 class HostFallbackWarning(UserWarning):
     """Fused decode silently routed some arrays to the numpy host path.
@@ -49,12 +52,24 @@ class HostFallbackWarning(UserWarning):
     the offending ``(name, width)`` pairs on :attr:`arrays`.  Stream-
     direct matmul avoids this entirely by lowering bundles at element
     granularity (every element width <= 32).
+
+    The message is the only constructor argument, and :attr:`arrays` is
+    parsed back out of it, so ``type(w)(*w.args)`` (how pytest-xdist
+    ships a warning from a worker) rebuilds an equal warning.  Build one
+    from pairs with :meth:`for_arrays`.
     """
 
-    def __init__(self, arrays: tuple[tuple[str, int], ...]):
-        self.arrays = arrays
+    _PAIR = re.compile(r"(\S+) \((\d+)b\)")
+
+    def __init__(self, message: str):
+        super().__init__(message)
+        detail = message.partition("host path: ")[2]
+        self.arrays = tuple((n, int(w)) for n, w in self._PAIR.findall(detail))
+
+    @classmethod
+    def for_arrays(cls, arrays) -> "HostFallbackWarning":
         detail = ", ".join(f"{n} ({w}b)" for n, w in arrays)
-        super().__init__(
+        return cls(
             f"decode_layout_fused: {len(arrays)} array(s) exceed the "
             f"32-bit kernel piece width and fell back to the host "
             f"path: {detail}. Lower at element granularity "
@@ -154,7 +169,7 @@ def decode_layout_fused(layout: Layout, buf_u8, *,
                         program: ExecProgram | None = None,
                         elem_widths: tuple[int, ...] | None = None,
                         tile_rows: int = DEFAULT_TILE_ROWS,
-                        interpret: bool = True) -> dict[str, jax.Array]:
+                        ) -> dict[str, jax.Array]:
     """Decode the whole packed buffer with a single ``pallas_call``.
 
     Pieces up to 32 bits wide go through the fused kernel; wider arrays
@@ -169,7 +184,8 @@ def decode_layout_fused(layout: Layout, buf_u8, *,
     outs: dict[str, jax.Array] = {}
     if prog.kernel.gathers:
         words = jnp.asarray(prog.buffer_words32(buf))
-        kern = _fused_grid_fn(prog, tile_rows, interpret)(words)
+        kern = _fused_grid_fn(prog, tile_rows,
+                              backend.interpret_mode())(words)
         for i, v in kern.items():
             outs[names[i]] = v
     if prog.host_arrays:
@@ -179,7 +195,7 @@ def decode_layout_fused(layout: Layout, buf_u8, *,
             if (sig, names[i]) not in _FALLBACK_WARNED)
         if fresh:
             _FALLBACK_WARNED.update((sig, n) for n, _w in fresh)
-            warnings.warn(HostFallbackWarning(fresh), stacklevel=2)
+            warnings.warn(HostFallbackWarning.for_arrays(fresh), stacklevel=2)
         flat = prog.buffer_words64(buf)
         for i in prog.host_arrays:
             # stays numpy uint64: jnp would truncate to 32 bits under the
@@ -212,18 +228,25 @@ def _decode_slot_kernel(in_ref, out_ref, *, offsets: tuple[int, ...],
     out_ref[...] = jnp.stack(cols, axis=1)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("offsets", "width", "n_rows", "tile_rows", "interpret"),
-)
 def decode_slot(rows_u32: jax.Array, *, offsets: tuple[int, ...], width: int,
-                n_rows: int, tile_rows: int = DEFAULT_TILE_ROWS,
-                interpret: bool = True) -> jax.Array:
+                n_rows: int, tile_rows: int = DEFAULT_TILE_ROWS) -> jax.Array:
     """Decode one (interval, slot) unit: ``n_rows`` bus rows -> codes.
 
     ``rows_u32`` is the (n_rows, words) u32 slab of the interval.  Returns
     (n_rows * lanes,) uint32 element codes in stream order.
     """
+    return _decode_slot(rows_u32, offsets=offsets, width=width,
+                        n_rows=n_rows, tile_rows=tile_rows,
+                        interpret=backend.interpret_mode())
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("offsets", "width", "n_rows", "tile_rows", "interpret"),
+)
+def _decode_slot(rows_u32: jax.Array, *, offsets: tuple[int, ...],
+                 width: int, n_rows: int, tile_rows: int,
+                 interpret: bool) -> jax.Array:
     lanes = len(offsets)
     words = rows_u32.shape[1]
     tile = min(tile_rows, _round_up(n_rows, 8))

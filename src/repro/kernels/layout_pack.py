@@ -43,6 +43,7 @@ from repro.core.exec_plan import (
 from repro.core.layout import Layout
 from repro.core.util import round_up as _round_up
 
+from . import backend
 from .layout_decode import HostFallbackWarning
 
 # Rows of the packed buffer produced per grid step.  The pack kernel
@@ -157,8 +158,7 @@ def _check_stream(name: str, a, depth: int, ew: int) -> np.ndarray:
 def pack_layout_fused(layout: Layout, arrays: dict, *,
                       program: ExecProgram | None = None,
                       elem_widths: tuple[int, ...] | None = None,
-                      tile_rows: int = DEFAULT_TILE_ROWS,
-                      interpret: bool = True) -> np.ndarray:
+                      tile_rows: int = DEFAULT_TILE_ROWS) -> np.ndarray:
     """Pack per-array piece streams with a single ``pallas_call``.
 
     Bit-identical to :func:`~repro.core.exec_plan.pack_compiled`: returns
@@ -185,7 +185,7 @@ def pack_layout_fused(layout: Layout, arrays: dict, *,
         for i, _g in prog.kernel.gathers:
             flat[1 + prog.piece_base[i]:1 + prog.piece_base[i + 1]] = \
                 streams[i].astype(np.uint32)
-        run = _fused_pack_fn(prog, tile_rows, interpret)
+        run = _fused_pack_fn(prog, tile_rows, backend.interpret_mode())
         out32 = np.asarray(jax.block_until_ready(run(jnp.asarray(flat))))
 
     if prog.host_arrays:
@@ -195,7 +195,7 @@ def pack_layout_fused(layout: Layout, arrays: dict, *,
             if (sig, names[i]) not in _FALLBACK_WARNED)
         if fresh:
             _FALLBACK_WARNED.update((sig, n) for n, _w in fresh)
-            warnings.warn(HostFallbackWarning(fresh), stacklevel=2)
+            warnings.warn(HostFallbackWarning.for_arrays(fresh), stacklevel=2)
         host_set = set(prog.host_arrays)
         host_data = [
             s if i in host_set else np.zeros_like(s)

@@ -32,10 +32,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
+
+from . import backend
 
 
 def _ssd_kernel(q_ref, k_ref, v_ref, logw_ref, o_ref, state_ref, *,
@@ -68,16 +67,21 @@ def _ssd_kernel(q_ref, k_ref, v_ref, logw_ref, o_ref, state_ref, *,
     state_ref[...] = jnp.exp(el[-1]) * s_in + (k * w_suffix[:, None]).T @ v
 
 
-@functools.partial(
-    jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_scan(q: jax.Array, k: jax.Array, v: jax.Array,
-             logw: jax.Array, *, chunk: int = 128,
-             interpret: bool = True) -> jax.Array:
+             logw: jax.Array, *, chunk: int = 128) -> jax.Array:
     """q/k: (B, T, H, dk), v: (B, T, H, dv), logw: (B, T, H) (<= 0).
 
     Returns out (B, T, H, dv) — the scalar-decay linear-attention scan.
     Requires T % chunk == 0 (pad upstream).
     """
+    return _ssd_scan(q, k, v, logw, chunk=chunk,
+                     interpret=backend.interpret_mode())
+
+
+@functools.partial(
+    jax.jit, static_argnames=("chunk", "interpret"))
+def _ssd_scan(q: jax.Array, k: jax.Array, v: jax.Array, logw: jax.Array,
+              *, chunk: int, interpret: bool) -> jax.Array:
     b, t, h, dk = q.shape
     dv = v.shape[-1]
     if t % chunk:

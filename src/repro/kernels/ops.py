@@ -55,7 +55,6 @@ def buffer_to_u32(buf_u8: np.ndarray | jax.Array) -> jax.Array:
 
 
 def decode_layout(layout: Layout, buf_u8: np.ndarray | jax.Array, *,
-                  interpret: bool = True,
                   plan: DecodePlan | None = None,
                   fused: bool | None = None,
                   program: ExecProgram | None = None,
@@ -76,8 +75,7 @@ def decode_layout(layout: Layout, buf_u8: np.ndarray | jax.Array, *,
     if fused is None:
         fused = plan is None
     if fused:
-        return decode_layout_fused(layout, buf_u8, program=program,
-                                   interpret=interpret)
+        return decode_layout_fused(layout, buf_u8, program=program)
     plan = plan if plan is not None else decode_plan(layout)
     words = buffer_to_u32(buf_u8)
     wide = [s for s in plan.slots if s.width > 32]
@@ -101,7 +99,6 @@ def decode_layout(layout: Layout, buf_u8: np.ndarray | jax.Array, *,
             offsets=offsets,
             width=slot.width,
             n_rows=slot.n_cycles,
-            interpret=interpret,
         )
         outs[slot.name] = jax.lax.dynamic_update_slice(
             outs[slot.name], codes, (slot.elem_base,)

@@ -25,10 +25,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pltpu is importable on CPU for scratch-shape declarations
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
+
+from . import backend
 
 #: element widths the lane-packed kernel path supports: the funnel shift
 #: needs a whole number of lanes per uint32 word (32 % bits == 0).  The
@@ -56,7 +55,8 @@ def _packed_matmul_kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *,
         ((w_packed >> jnp.uint32(ln * bits)) & mask) for ln in range(lanes)
     ]
     codes = jnp.stack(planes, axis=1).reshape(bk, bn)
-    wq = codes.astype(jnp.float32) - bias      # symmetric biased codes
+    # via int32: Mosaic has no uint32 -> float32 conversion
+    wq = codes.astype(jnp.int32).astype(jnp.float32) - bias
     scales = s_ref[...].astype(jnp.float32)    # (bk // group_size, bn)
     wf = (wq.reshape(bk // group_size, group_size, bn)
           * scales[:, None, :]).reshape(bk, bn)
@@ -68,17 +68,10 @@ def _packed_matmul_kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *,
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "bits", "group_size", "block_m", "block_n", "block_k", "interpret",
-        "out_dtype",
-    ),
-)
 def packed_matmul(x: jax.Array, w_packed: jax.Array, scales: jax.Array, *,
                   bits: int, group_size: int, block_m: int = 128,
                   block_n: int = 128, block_k: int = 512,
-                  out_dtype=jnp.float32, interpret: bool = True) -> jax.Array:
+                  out_dtype=jnp.float32) -> jax.Array:
     """``x @ dequant(w_packed, scales)`` with on-the-fly dequantization.
 
     x:        (M, K) float
@@ -86,6 +79,24 @@ def packed_matmul(x: jax.Array, w_packed: jax.Array, scales: jax.Array, *,
               (see ``quant.pack_codes_u32``)
     scales:   (K // group_size, N)
     """
+    return packed_matmul_call(
+        x, w_packed, scales, bits=bits, group_size=group_size,
+        block_m=block_m, block_n=block_n, block_k=block_k,
+        out_dtype=out_dtype, interpret=backend.interpret_mode())
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=(
+        "bits", "group_size", "block_m", "block_n", "block_k", "interpret",
+        "out_dtype",
+    ),
+)
+def packed_matmul_call(x: jax.Array, w_packed: jax.Array, scales: jax.Array,
+                       *, bits: int, group_size: int, block_m: int,
+                       block_n: int, block_k: int, out_dtype,
+                       interpret: bool) -> jax.Array:
+    """The jitted kernel launch behind :func:`packed_matmul`."""
     if bits not in SUPPORTED_BITS:
         raise ValueError(
             f"packed_matmul supports bits in {sorted(SUPPORTED_BITS)}; "
@@ -98,7 +109,7 @@ def packed_matmul(x: jax.Array, w_packed: jax.Array, scales: jax.Array, *,
         raise ValueError(f"packed K mismatch: {kw}*{lanes} != {k}")
     if scales.shape != (k // group_size, n):
         raise ValueError(f"scales shape {scales.shape} != {(k // group_size, n)}")
-    block_m = min(block_m, m)
+    block_m = min(block_m, -(-m // 8) * 8)
     block_n = min(block_n, n)
     block_k = min(block_k, k)
     if k % block_k or block_k % group_size:

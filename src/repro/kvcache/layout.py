@@ -27,8 +27,10 @@ rebinds):
   K/V code and scale (the :class:`~repro.core.exec_plan.StreamTables`
   convention: word index ``tab >> 5``, shift ``tab & 31``).
 * :func:`full_stream_tables` — the per-page tables broadcast across
-  ``n_pages`` by adding each page's bit stride, giving the attention
-  prologue one flat (smax, ...) table over a slot's concatenated pages.
+  ``n_pages`` by adding each page's bit stride: one flat (smax, ...)
+  table over a slot's concatenated pages (the host oracles' input).
+* :func:`page_window_tables` — the attention kernel's form: page-local
+  window entries (:mod:`repro.kernels.window`) that every page shares.
 """
 from __future__ import annotations
 
@@ -38,6 +40,7 @@ import numpy as np
 
 from repro.core.exec_plan import ExecProgram, pack_kernel_tables
 from repro.core.packing import BundleTensor
+from repro.kernels.window import LANES, encode_entries, split_offsets
 
 #: bundle array order (index into the lowered program's arrays)
 KV_ARRAYS = ("kv/k", "kv/k_scales", "kv/v", "kv/v_scales")
@@ -242,3 +245,44 @@ def full_stream_tables(prog: ExecProgram, *, page_tokens: int,
             .astype(np.uint32)
     prog.jit_cache[key] = full
     return full
+
+
+def page_window_tables(prog: ExecProgram, *, page_tokens: int,
+                       n_kv_heads: int, head_dim: int, bits: int
+                       ) -> dict[str, np.ndarray]:
+    """Page-local window entries for the stream-attention kernel.
+
+    A page's words are staged as ``n_chunks`` rows of 128 words; an
+    element's source row is its chunk (``word >> 7``) and every page
+    shares the same entries.  Each table is ``(n_kv_heads, page_tokens,
+    128)`` uint32 (:func:`repro.kernels.window.encode_entries`): ``k`` /
+    ``v`` hold head dim ``d`` in lane ``d`` (lanes past ``head_dim``
+    repeat lane 0), ``k_scales`` / ``v_scales`` repeat the (token, head)
+    scale across all lanes, so the extracted scales broadcast over the
+    head dim for free.
+    """
+    if head_dim > LANES:
+        raise ValueError(
+            f"head_dim {head_dim} > {LANES}: stream attention holds one "
+            "head vector per 128-lane row")
+    key = ("kv_window", page_tokens, n_kv_heads, head_dim, bits)
+    cached = prog.jit_cache.get(key)
+    if cached is not None:
+        return cached
+    page = page_stream_tables(prog, page_tokens=page_tokens,
+                              n_kv_heads=n_kv_heads, head_dim=head_dim)
+    shape = (n_kv_heads, page_tokens, LANES)
+    out = {}
+    for name, tab in page.items():
+        if tab.ndim == 3:                       # (pt, hkv, hd) codes
+            t = np.empty(shape, dtype=np.int64)
+            t[..., :head_dim] = tab.transpose(1, 0, 2)
+            t[..., head_dim:] = t[..., :1]
+            width = bits
+        else:                                   # (pt, hkv) scales
+            t = np.broadcast_to(tab.T[..., None].astype(np.int64), shape)
+            width = 16
+        lo, hi, sh = split_offsets(t, width)
+        out[name] = encode_entries(lo, sh, lo >> 7, hi >> 7)
+    prog.jit_cache[key] = out
+    return out

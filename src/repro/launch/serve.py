@@ -22,6 +22,12 @@ legacy kernel views; every other width (3/5/6/7) serves *stream-direct*
 (``kernels.stream_matmul``), no dense intermediate — with host->device
 uploads double-buffered by :class:`repro.engine.StreamUploader` so the
 next layer's transfer overlaps the current layer's compute.
+
+:func:`build_engine` is the construction both this CLI and
+``chip_smoke.py`` use.  The Pallas kernels run compiled on a TPU and in
+interpret mode elsewhere (:mod:`repro.kernels.backend`); compiled
+programs persist in the cache :func:`repro.launch.compile_cache.enable`
+sets up.
 """
 from __future__ import annotations
 
@@ -54,6 +60,72 @@ def _run_open_loop(engine, requests, qps: float,
             time.sleep(min(0.001, arrivals[0][0] - now))
 
 
+def build_engine(cfg, model, params, *, packed: bool, bits: int = 8,
+                 kv: str = "dense", batch_size: int, max_seq: int,
+                 policy: str = "continuous"):
+    """Build the serving :class:`~repro.engine.Engine` for ``cfg``.
+
+    ``packed`` serves quantized weights through ``repro.api.pack_tree``
+    (``bits`` in 2..8; widths in ``SUPPORTED_BITS`` read the lane-packed
+    kernel views, the rest stream-direct); ``kv="packed"`` stores the KV
+    cache as packed pages at the weight width.  Prints the packing
+    summary lines; the caller closes ``engine.adapter.uploader`` (set
+    for stream-direct serving) when done.
+    """
+    from repro.engine import (
+        DenseAdapter,
+        Engine,
+        EngineConfig,
+        PackedAdapter,
+        StreamUploader,
+    )
+
+    if packed:
+        from repro import api
+        from repro.models.quantized import bytes_per_token_report, quantizable
+        from repro.quant import QuantSpec
+
+        if not quantizable(cfg):
+            raise SystemExit(f"{cfg.name}: packed path covers dense archs")
+        qspec = QuantSpec(bits=bits, group_size=32)
+
+        # the one front door: quantize -> plan (cached) -> pack streams
+        pt = api.pack_tree(cfg, params, qspec)
+        rep = bytes_per_token_report(cfg, pt)
+        print(f"weight stream/token: packed={rep['packed_MiB']:.2f} MiB "
+              f"padded-int={rep['padded_int_MiB']:.2f} "
+              f"bf16={rep['bf16_MiB']:.2f} "
+              f"({rep['bf16_MiB']/rep['packed_MiB']:.2f}x reduction)")
+        print(pt.summary())
+        # per-layer plan summary: the shared cache answers by signature,
+        # so this never re-runs the scheduler
+        print(api.plan(pt.manifest.problem()).summary())
+
+        # compiled execution plan (one per layout signature, shared by
+        # every layer through the layout cache): the whole stream decodes
+        # with a single fused Pallas kernel per layer
+        prog = pt.exec_program()
+        print(f"exec program: pieces={prog.n_pieces}, "
+              f"kernel lanes={prog.kernel.lanes}, "
+              f"host-path arrays={len(prog.host_arrays)}, "
+              f"pallas calls/decode={prog.n_pallas_calls}")
+
+        mode = "kernel-views" if pt.packed else "stream-direct"
+        # stream-direct serving: double-buffer the per-layer stream
+        # uploads so transfer overlaps decode
+        uploader = None if pt.packed else StreamUploader(pt)
+        print(f"serving path: {mode} (int{bits}, kv={kv})")
+        adapter = PackedAdapter(cfg, pt, uploader=uploader, kv=kv)
+    else:
+        if kv != "dense":
+            raise ValueError("packed KV pages need packed=True")
+        adapter = DenseAdapter(model, params)
+
+    return Engine(adapter, EngineConfig(
+        batch_size=batch_size, max_seq=max_seq, max_backlog=None,
+        policy=policy))
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
@@ -84,16 +156,11 @@ def main() -> None:
     args = ap.parse_args()
 
     from repro.configs import get_config
-    from repro.engine import (
-        DenseAdapter,
-        Engine,
-        EngineConfig,
-        EngineRequest,
-        PackedAdapter,
-        StreamUploader,
-    )
+    from repro.engine import EngineRequest
+    from repro.launch import compile_cache
     from repro.models.model import Model
 
+    compile_cache.enable()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -101,50 +168,10 @@ def main() -> None:
     params = model.init(jax.random.PRNGKey(args.seed))
     rng = np.random.default_rng(args.seed)
 
-    uploader = None
-    if args.packed:
-        from repro import api
-        from repro.models.quantized import bytes_per_token_report, quantizable
-        from repro.quant import QuantSpec
-
-        if not quantizable(cfg):
-            raise SystemExit(f"{cfg.name}: packed path covers dense archs")
-        qspec = QuantSpec(bits=args.bits, group_size=32)
-
-        # the one front door: quantize -> plan (cached) -> pack streams
-        pt = api.pack_tree(cfg, params, qspec)
-        rep = bytes_per_token_report(cfg, pt)
-        print(f"weight stream/token: packed={rep['packed_MiB']:.2f} MiB "
-              f"padded-int={rep['padded_int_MiB']:.2f} "
-              f"bf16={rep['bf16_MiB']:.2f} "
-              f"({rep['bf16_MiB']/rep['packed_MiB']:.2f}x reduction)")
-        print(pt.summary())
-        # per-layer plan summary: the shared cache answers by signature,
-        # so this never re-runs the scheduler
-        print(api.plan(pt.manifest.problem()).summary())
-
-        # compiled execution plan (one per layout signature, shared by
-        # every layer through the layout cache): the whole stream decodes
-        # with a single fused Pallas kernel per layer
-        prog = pt.exec_program()
-        print(f"exec program: pieces={prog.n_pieces}, "
-              f"kernel lanes={prog.kernel.lanes}, "
-              f"host-path arrays={len(prog.host_arrays)}, "
-              f"pallas calls/decode={prog.n_pallas_calls}")
-
-        mode = "kernel-views" if pt.packed else "stream-direct"
-        if not pt.packed:
-            # stream-direct serving: double-buffer the per-layer stream
-            # uploads so transfer overlaps decode
-            uploader = StreamUploader(pt)
-        print(f"serving path: {mode} (int{args.bits})")
-        adapter = PackedAdapter(cfg, pt, interpret=True, uploader=uploader)
-    else:
-        adapter = DenseAdapter(model, params)
-
-    engine = Engine(adapter, EngineConfig(
-        batch_size=args.batch_size, max_seq=args.max_seq,
-        max_backlog=None, policy=args.policy))
+    engine = build_engine(cfg, model, params, packed=args.packed,
+                          bits=args.bits, batch_size=args.batch_size, max_seq=args.max_seq,
+                          policy=args.policy)
+    uploader = getattr(engine.adapter, "uploader", None)
     requests = []
     for uid in range(args.requests):
         prompt = rng.integers(1, cfg.vocab_size,

@@ -155,8 +155,7 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
 
 def stream_decode_attention(kvc, q: jax.Array, pos: jax.Array,
                             slot_ids: jax.Array, *, layer: int,
-                            oracle: bool = False,
-                            interpret: bool = True) -> jax.Array:
+                            oracle: bool = False) -> jax.Array:
     """Decode attention straight off a packed Iris KV stream.
 
     ``kvc`` is a :class:`repro.kvcache.PackedKVCache`; ``q``:
@@ -172,8 +171,7 @@ def stream_decode_attention(kvc, q: jax.Array, pos: jax.Array,
         return decode_attention(q, kf, vf, pos)
     from repro.kvcache.kernels import stream_attention_cache  # lazy
 
-    return stream_attention_cache(kvc, q, pos, slot_ids, layer=layer,
-                                  interpret=interpret)
+    return stream_attention_cache(kvc, q, pos, slot_ids, layer=layer)
 
 
 # ----------------------------------------------------------------------

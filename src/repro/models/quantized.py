@@ -70,22 +70,44 @@ def __getattr__(name: str):
     raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 
-def _pmm(x2d, pw, sc, spec, interpret):
-    """x2d: (B, K) @ packed (K*bits/32, N) -> (B, N).  Pads B to the MXU
-    tile, K blocks to the group size."""
-    b, k = x2d.shape
+#: matmul tiles: K in 512-row steps, N in 128-lane columns
+_TILE_K, _TILE_N = 512, 128
+
+
+def _blocks(k: int, n: int, group_size: int) -> dict[str, int]:
+    """Tile-legal blocks for a ``(K, N)`` decode matmul.
+
+    A dimension the tile divides is tiled; otherwise its block is the
+    whole dimension (always legal on a TPU).  ``block_k`` stays a
+    multiple of ``group_size``: the tile only when the group divides it,
+    and K itself is a group multiple.  At smollm-135m widths this gives
+    K=576 -> 576, K=1536 -> 512, N=192/576 -> whole, N=1536 -> 128.
+    """
+    bk = _TILE_K if k % _TILE_K == 0 and _TILE_K % group_size == 0 else k
+    bn = _TILE_N if n % _TILE_N == 0 else n
+    return {"block_k": bk, "block_n": bn}
+
+
+def _pad_rows(x2d):
+    """Pad the batch rows to a power-of-two MXU tile (at least 8)."""
+    b = x2d.shape[0]
     bm = max(8, 1 << (b - 1).bit_length())
     if bm != b:
         x2d = jnp.pad(x2d, ((0, bm - b), (0, 0)))
-    n = pw.shape[1]
+    return x2d, bm
+
+
+def _pmm(x2d, pw, sc, spec):
+    """x2d: (B, K) @ packed (K*bits/32, N) -> (B, N)."""
+    b, k = x2d.shape
+    x2d, bm = _pad_rows(x2d)
     out = packed_matmul(
         x2d, pw, sc, bits=spec.bits, group_size=spec.group_size,
-        block_m=bm, block_n=min(128, n), block_k=min(512, k),
-        interpret=interpret)
+        block_m=bm, **_blocks(k, pw.shape[1], spec.group_size))
     return out[:b]
 
 
-def _pmm_direct(x2d, pp, name, layer, interpret, words=None):
+def _pmm_direct(x2d, pp, name, layer, words=None):
     """Stream-direct twin of :func:`_pmm`: same B padding and block
     choices, but the weights are gathered straight from the layer's
     packed Iris stream (``kernels.stream_matmul``) — no lane-packed
@@ -93,18 +115,15 @@ def _pmm_direct(x2d, pp, name, layer, interpret, words=None):
     ``words`` optionally supplies the layer's stream word view from an
     external stage (see :meth:`~repro.tree.PackedTree.matmul_direct`)."""
     b, k = x2d.shape
-    bm = max(8, 1 << (b - 1).bit_length())
-    if bm != b:
-        x2d = jnp.pad(x2d, ((0, bm - b), (0, 0)))
-    n = pp.shapes[name][1]
+    x2d, bm = _pad_rows(x2d)
     out = pp.matmul_direct(
-        x2d, name, layer, interpret=interpret, words=words,
-        block_m=bm, block_n=min(128, n), block_k=min(512, k))
+        x2d, name, layer, words=words, block_m=bm,
+        **_blocks(k, pp.shapes[name][1], pp.spec.group_size))
     return out[:b]
 
 
 def packed_decode_step(cfg: ModelConfig, pp: "PackedTree", state: dict,
-                       tokens: jax.Array, *, interpret: bool = True,
+                       tokens: jax.Array, *,
                        weights: str = "auto", slot_ids=None,
                        stream_source=None, kv: str = "dense",
                        kv_attention: str = "stream"
@@ -199,9 +218,9 @@ def packed_decode_step(cfg: ModelConfig, pp: "PackedTree", state: dict,
     def mm(name, period, x2d, words=None):
         if use_stream:
             return _pmm_direct(x2d.astype(jnp.float32), pp, name, period,
-                               interpret, words=words)
+                               words=words)
         return _pmm(x2d.astype(jnp.float32), pp.packed[name][period],
-                    pp.scales[name][period], spec, interpret)
+                    pp.scales[name][period], spec)
 
     np_ = n_periods(cfg)
     k_cache, v_cache = state["k_cache"], state["v_cache"]
@@ -224,7 +243,7 @@ def packed_decode_step(cfg: ModelConfig, pp: "PackedTree", state: dict,
             kvc = kvc.append(kk[:, 0], vv[:, 0], pos, rows, layer=layer)
             att = attn.stream_decode_attention(
                 kvc, q.astype(jnp.bfloat16), pos, rows, layer=layer,
-                oracle=kv_attention == "dense", interpret=interpret)
+                oracle=kv_attention == "dense")
         else:
             kc = k_cache[layer].at[rows, pos].set(
                 kk[:, 0].astype(k_cache.dtype))

@@ -358,8 +358,8 @@ class PackedTree:
             self._stream_words[layer] = words
         return words
 
-    def matmul_direct(self, x, key: str, layer: int, *,
-                      interpret: bool = True, words=None, **block_kw):
+    def matmul_direct(self, x, key: str, layer: int, *, words=None,
+                      **block_kw):
         """``x @ dequant(key)`` gathered straight from layer ``layer``'s
         packed stream — the serving path that never materializes a dense
         weight intermediate, for any element width <= 32 (including the
@@ -370,17 +370,11 @@ class PackedTree:
         :class:`~repro.engine.streams.StreamUploader`) to matmul against
         an externally staged buffer instead of the tree's resident copy.
         """
-        import jax.numpy as jnp
-
         from repro.kernels.stream_matmul import stream_matmul
 
-        tabs = self.stream_tables(key)
         if words is None:
             words = self.layer_stream_words(layer)
-        return stream_matmul(
-            x, words, jnp.asarray(tabs.w_tab),
-            jnp.asarray(tabs.s_tab), bits=tabs.bits,
-            group_size=tabs.group_size, interpret=interpret, **block_kw)
+        return stream_matmul(x, words, self.stream_tables(key), **block_kw)
 
     # -- verification ---------------------------------------------------
     def verify(self, *, raise_on_error: bool = True, passes=None):

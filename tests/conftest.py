@@ -108,7 +108,7 @@ def two_pass_oracle(x, lay, prog, buf, bits: int, group_size: int,
     from repro.kernels.packed_matmul import SUPPORTED_BITS, packed_matmul
 
     g = group_size
-    dec = decode_layout_fused(lay, buf, program=prog, interpret=True)
+    dec = decode_layout_fused(lay, buf, program=prog)
     codes = np.asarray(dec["w"])[:k * n].reshape(k, n)
     scales = jax.lax.bitcast_convert_type(
         jnp.asarray(np.asarray(dec["w_scales"])[:(k // g) * n]
@@ -121,8 +121,7 @@ def two_pass_oracle(x, lay, prog, buf, bits: int, group_size: int,
     from repro.quant import pack_codes_u32
     pw = pack_codes_u32(jnp.asarray(codes.astype(np.uint8)), mm_bits)
     return packed_matmul(x, pw, scales, bits=mm_bits, group_size=g,
-                         block_m=block_m, block_n=block_n, block_k=block_k,
-                         interpret=True)
+                         block_m=block_m, block_n=block_n, block_k=block_k)
 
 
 # ----------------------------------------------------------------------

@@ -35,7 +35,7 @@ def test_cross_backend_equivalence(strategy, prob_name):
     codes = api.random_codes(prob, seed=7)
     buf = pl.pack(codes)
     out_np = pl.decode(buf, backend="numpy")
-    out_pl = pl.decode(buf, backend="pallas", interpret=True)
+    out_pl = pl.decode(buf, backend="pallas")
     for name, want in codes.items():
         assert np.array_equal(out_np[name], want), (strategy, name)
         assert np.array_equal(out_pl[name], out_np[name]), (strategy, name)

@@ -389,7 +389,7 @@ def _oracle_tokens(cfg, model, tree, req):
     while len(generated) < req.max_new_tokens and pos < 31:
         tok = req.prompt[pos] if pos < len(req.prompt) else generated[-1]
         logits, state = packed_decode_step(
-            cfg, tree, state, jnp.asarray([tok], jnp.int32), interpret=True)
+            cfg, tree, state, jnp.asarray([tok], jnp.int32))
         pos += 1
         if pos >= len(req.prompt):
             generated.append(int(np.asarray(logits[0]).argmax()))
@@ -432,11 +432,9 @@ def test_ragged_step_rows_bit_identical_to_full_batch(packed_setup):
     tree = trees[3]
     state = model.init_decode_state(4, 16)
     full, _ = packed_decode_step(cfg, tree, state,
-                                 jnp.asarray([5, 6, 7, 8], jnp.int32),
-                                 interpret=True)
+                                 jnp.asarray([5, 6, 7, 8], jnp.int32))
     ragged, st = packed_decode_step(cfg, tree, state,
                                     jnp.asarray([6, 8], jnp.int32),
-                                    interpret=True,
                                     slot_ids=jnp.asarray([1, 3], jnp.int32))
     assert (np.asarray(full)[[1, 3]] == np.asarray(ragged)).all()
     assert np.asarray(st["pos"]).tolist() == [0, 1, 0, 1]

@@ -52,8 +52,8 @@ class TestFusedDecode:
         lay = schedule(p)
         codes = random_codes(p, seed=prob_idx)
         buf = pack_compiled(lay, codes)
-        fused = decode_layout(lay, buf, interpret=True, fused=True)
-        legacy = decode_layout(lay, buf, interpret=True, fused=False)
+        fused = decode_layout(lay, buf, fused=True)
+        legacy = decode_layout(lay, buf, fused=False)
         for name, want in codes.items():
             np.testing.assert_array_equal(
                 np.asarray(fused[name]).astype(np.uint64), want)
@@ -79,7 +79,7 @@ class TestFusedDecode:
             return real(*a, **kw)
 
         monkeypatch.setattr(ld.pl, "pallas_call", counting)
-        out = ld.decode_layout_fused(lay, buf, interpret=True)
+        out = ld.decode_layout_fused(lay, buf)
         assert len(calls) == prog.n_pallas_calls == 1
         for name, want in codes.items():
             np.testing.assert_array_equal(
@@ -97,7 +97,7 @@ class TestFusedDecode:
         prog = lower_exec(lay)
         assert prog.host_arrays == (1, 2)
         for fused in (True, False):
-            got = decode_layout(lay, buf, interpret=True, fused=fused)
+            got = decode_layout(lay, buf, fused=fused)
             for name, want in codes.items():
                 np.testing.assert_array_equal(
                     np.asarray(got[name]).astype(np.uint64), want)

@@ -45,8 +45,8 @@ def test_fused_decode_matches_per_slot(problem, seed):
     lay = schedule(problem)
     codes = random_codes(problem, seed=seed)
     buf = pack_compiled(lay, codes)
-    fused = decode_layout(lay, buf, interpret=True, fused=True)
-    legacy = decode_layout(lay, buf, interpret=True, fused=False)
+    fused = decode_layout(lay, buf, fused=True)
+    legacy = decode_layout(lay, buf, fused=False)
     for name, want in codes.items():
         assert np.array_equal(
             np.asarray(fused[name]).astype(np.uint64), want)

@@ -1,6 +1,6 @@
 """Per-kernel tests: shape/dtype sweeps asserting allclose vs ref.py oracles.
 
-All Pallas kernels run in interpret=True mode (CPU container; TPU is the
+All Pallas kernels run in interpret mode (CPU backend; TPU is the
 lowering target)."""
 import jax
 import jax.numpy as jnp
@@ -33,7 +33,7 @@ class TestDecodeSlot:
         max_off = (words - 1) * 32 - width
         offsets = tuple(sorted(rng.integers(0, max_off, size=3).tolist()))
         got = decode_slot(jnp.asarray(rows), offsets=offsets, width=width,
-                          n_rows=n_rows, interpret=True)
+                          n_rows=n_rows)
         want = decode_slot_ref(rows, offsets, width, n_rows)
         np.testing.assert_array_equal(np.asarray(got), want)
 
@@ -45,7 +45,7 @@ class TestDecodeSlot:
         for width in (17, 24, 31):
             off = 32 - (width // 2)          # deliberately straddles
             got = decode_slot(jnp.asarray(rows), offsets=(off,), width=width,
-                              n_rows=64, interpret=True)
+                              n_rows=64)
             want = decode_slot_ref(rows, (off,), width, 64)
             np.testing.assert_array_equal(np.asarray(got), want)
 
@@ -64,7 +64,7 @@ class TestDecodeLayout:
         codes = random_codes(p, seed=prob_idx)
         buf = pack_arrays(lay, codes)
         ref = decode_layout_ref(lay, buf)
-        got = decode_layout(lay, buf, interpret=True)
+        got = decode_layout(lay, buf)
         for name, want in codes.items():
             np.testing.assert_array_equal(
                 np.asarray(got[name], dtype=np.uint64), ref[name])
@@ -95,7 +95,7 @@ class TestPackedMatmul:
         pw = pack_codes_u32(qt.codes, bits)
         x = jax.random.normal(jax.random.PRNGKey(1), (m, k), jnp.float32)
         got = packed_matmul(x, pw, qt.scales, bits=bits, group_size=128,
-                            block_m=min(128, m), block_k=256, interpret=True)
+                            block_m=min(128, m), block_k=256)
         want = packed_matmul_ref(x, pw, qt.scales, bits=bits, group_size=128)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-5, atol=1e-4)
@@ -108,7 +108,7 @@ class TestPackedMatmul:
         pw = pack_codes_u32(qt.codes, 4)
         x = jax.random.normal(jax.random.PRNGKey(3), (32, 256)).astype(x_dtype)
         got = packed_matmul(x, pw, qt.scales, bits=4, group_size=64,
-                            block_m=32, block_k=128, interpret=True)
+                            block_m=32, block_k=128)
         want = packed_matmul_ref(x, pw, qt.scales, bits=4, group_size=64)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-2, atol=2e-2)
@@ -120,7 +120,7 @@ class TestPackedMatmul:
         qt = quantize(w, spec)
         x = jax.random.normal(jax.random.PRNGKey(5), (64, 512), jnp.float32)
         got = packed_matmul(x, pack_codes_u32(qt.codes, 4), qt.scales,
-                            bits=4, group_size=128, interpret=True)
+                            bits=4, group_size=128)
         want = x @ dequantize(qt)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-4, atol=1e-3)
@@ -134,7 +134,7 @@ class TestPackedMatmul:
         pw = pack_codes_u32(qt.codes, 4)
         x = jax.random.normal(jax.random.PRNGKey(7), (m, 256), jnp.float32)
         got = packed_matmul(x, pw, qt.scales, bits=4, group_size=64,
-                            block_m=64, block_k=128, interpret=True)
+                            block_m=64, block_k=128)
         want = packed_matmul_ref(x, pw, qt.scales, bits=4, group_size=64)
         assert got.shape == (m, 128)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -145,14 +145,14 @@ class TestPackedMatmul:
         pw = jnp.zeros((256 * 4 // 32, 128), jnp.uint32)
         s = jnp.ones((2, 128))
         with pytest.raises(ValueError):
-            packed_matmul(x, pw, s, bits=4, group_size=100, interpret=True)
+            packed_matmul(x, pw, s, bits=4, group_size=100)
         with pytest.raises(ValueError):
             packed_matmul(x, jnp.zeros((3, 128), jnp.uint32), s, bits=4,
-                          group_size=128, interpret=True)
+                          group_size=128)
         # genuinely invalid N tiling still errors
         with pytest.raises(ValueError):
             packed_matmul(x, pw, jnp.ones((2, 128)), bits=4, group_size=128,
-                          block_n=96, interpret=True)
+                          block_n=96)
 
 
 # ----------------------------------------------------------------------

@@ -262,7 +262,7 @@ def test_packed_decode_step_stream_vs_dense_oracle():
         for tok in ([5, 9], [7, 3]):
             logits, state = packed_decode_step(
                 cfg, tree, state, jnp.asarray(tok, jnp.int32),
-                interpret=True, kv="packed", kv_attention=kv_attention)
+                kv="packed", kv_attention=kv_attention)
             outs.append(np.asarray(logits))
         return outs, state
 
@@ -276,11 +276,9 @@ def test_packed_decode_step_stream_vs_dense_oracle():
     state["packed_kv"] = PackedKVCache.create(
         cfg, bits=4, page_tokens=4, n_slots=2, max_seq=16)
     full, _ = packed_decode_step(cfg, tree, state,
-                                 jnp.asarray([5, 9], jnp.int32),
-                                 interpret=True, kv="packed")
+                                 jnp.asarray([5, 9], jnp.int32), kv="packed")
     ragged, st = packed_decode_step(cfg, tree, state,
-                                    jnp.asarray([9], jnp.int32),
-                                    interpret=True, kv="packed",
+                                    jnp.asarray([9], jnp.int32), kv="packed",
                                     slot_ids=jnp.asarray([1], jnp.int32))
     assert (np.asarray(full)[[1]] == np.asarray(ragged)).all()
     assert np.asarray(st["pos"]).tolist() == [0, 1]

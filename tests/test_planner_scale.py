@@ -2,7 +2,7 @@
 cache tier, and the LayoutCache internals ISSUE-9 calls out as untested.
 
 Everything here must hold on a 1-core container: the pool path is
-exercised by monkeypatching ``os.cpu_count`` (fork start method works
+exercised by monkeypatching ``os.cpu_count`` (spawned workers run
 with 1 core; the processes just time-share), and every speed claim is
 checked as *bit-equivalence*, never wall-clock.
 """

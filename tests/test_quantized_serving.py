@@ -31,6 +31,70 @@ def dense_setup():
     return cfg, model, params
 
 
+@pytest.mark.parametrize("bits", [4, 3])
+def test_published_width_layer_decodes(bits):
+    """One smollm-135m layer at published widths (d_model 576, d_ff
+    1536, 9/3 heads, head_dim 64) decodes through ``packed_decode_step``:
+    int4 on the lane-packed views, int3 stream-direct.  Guards the
+    tile-legal block choice (K=576 and N=192/576 are whole-dimension
+    blocks) and agrees with the float32 path over the dequantized
+    weights."""
+    import dataclasses
+
+    from repro.quant.qtypes import dequantize, quantize
+
+    cfg = dataclasses.replace(get_config("smollm-135m"), n_layers=1,
+                              vocab_size=512)
+    model = Model(cfg, remat="none")
+    params = model.init(jax.random.PRNGKey(1))
+    spec = QuantSpec(bits=bits, group_size=32)
+    pp = api.pack_tree(cfg, params, spec)
+    assert bool(pp.packed) == (bits == 4)
+    state = model.init_decode_state(2, max_seq=8)
+    toks = jnp.array([5, 9], jnp.int32)
+    got, _ = packed_decode_step(cfg, pp, state, toks)
+
+    cfg32 = dataclasses.replace(cfg, dtype="float32")
+    ref = jax.tree.map(lambda a: a.astype(jnp.float32), params)
+    for sub in ("attn", "mlp"):
+        for name, w in params["blocks"][0][sub].items():
+            ref["blocks"][0][sub][name] = jax.vmap(
+                lambda wl: dequantize(quantize(wl, spec)))(w)
+    m32 = Model(cfg32, remat="none")
+    with jax.default_matmul_precision("highest"):
+        want, _ = m32.decode_step(ref, m32.init_decode_state(2, 8), toks)
+    got, want = np.asarray(got, np.float32), np.asarray(want)
+    assert got.shape == (2, cfg.vocab_size) and np.isfinite(got).all()
+    # bf16 embeddings, norms and attention operands on the packed path
+    assert np.abs(got - want).max() <= 0.05 * np.abs(want).max()
+    assert (got.argmax(-1) == want.argmax(-1)).all()
+
+
+def test_compile_cache_env_wins(monkeypatch):
+    """A set ``JAX_COMPILATION_CACHE_DIR`` is left to JAX: ``enable``
+    reports it and configures no other directory."""
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv(compile_cache.ENV, "given-cache")
+    assert compile_cache.enable() == "given-cache"
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_default_is_checkout(monkeypatch, tmp_path):
+    """Unset, the cache is ``<checkout>/.jax_cache``; a package imported
+    from anywhere but a checkout raises instead of guessing a path."""
+    from repro.launch import compile_cache
+
+    path = compile_cache.default_dir()
+    assert path.name == ".jax_cache"
+    assert (path.parent / "pyproject.toml").is_file()
+    monkeypatch.setattr(compile_cache, "CHECKOUT", tmp_path)
+    monkeypatch.delenv(compile_cache.ENV, raising=False)
+    with pytest.raises(RuntimeError, match="not imported from a checkout"):
+        compile_cache.enable()
+
+
 def test_quantizable_families():
     assert quantizable(get_config("smollm-135m").reduced())
     assert quantizable(get_config("mistral-large-123b").reduced())
@@ -48,7 +112,7 @@ def test_packed_decode_matches_dense(dense_setup):
     dense_logits, dense_state = jax.jit(model.decode_step)(
         params, state, toks, None)
     packed_logits, packed_state = packed_decode_step(
-        cfg, pp, state, toks, interpret=True)
+        cfg, pp, state, toks)
     # rank agreement on the top prediction + bounded numeric gap
     d = np.asarray(dense_logits, np.float32)
     q = np.asarray(packed_logits, np.float32)
@@ -63,8 +127,7 @@ def test_multi_step_packed_generation(dense_setup):
     state = model.init_decode_state(2, max_seq=16)
     toks = jnp.array([5, 9], jnp.int32)
     for i in range(4):
-        logits, state = packed_decode_step(cfg, pp, state, toks,
-                                           interpret=True)
+        logits, state = packed_decode_step(cfg, pp, state, toks)
         assert np.isfinite(np.asarray(logits, np.float32)).all()
         toks = jnp.argmax(logits, -1).astype(jnp.int32)
     assert (np.asarray(state["pos"]) == 4).all()

@@ -16,8 +16,8 @@ For widths packed_matmul cannot lane-pack (3/5/6/7), the oracle
 re-biases codes into 8-bit containers, which preserves every
 dequantized float exactly — see ``conftest.two_pass_oracle``.
 
-All kernels run interpret=True (CPU container; TPU is the lowering
-target).
+On a CPU backend every kernel runs in Pallas interpret mode
+(``repro.kernels.backend``); the TPU is the lowering target.
 """
 import numpy as np
 import pytest
@@ -43,8 +43,7 @@ def _x(m, k, seed=0):
 def _run(case, x, **kw):
     _, _, _, prog, buf, tabs = case
     sw = stream_words(prog, buf)
-    return stream_matmul(x, sw, tabs.w_tab, tabs.s_tab, bits=tabs.bits,
-                         group_size=tabs.group_size, interpret=True, **kw)
+    return stream_matmul(x, sw, tabs, **kw)
 
 
 # ----------------------------------------------------------------------
@@ -171,10 +170,18 @@ class TestHostFallback:
         lay = schedule(p)
         buf = pack_compiled(lay, random_codes(p, seed=0))
         with pytest.warns(HostFallbackWarning) as rec:
-            decode_layout_fused(lay, buf, interpret=True)
+            decode_layout_fused(lay, buf)
         w = rec[0].message
         assert ("w", 40) in w.arrays
         assert "40" in str(w) and "w" in str(w.arrays[0])
+
+    def test_fallback_warning_rebuilds_from_args(self):
+        """pytest-xdist ships a worker's warning as ``cls(*args)``; the
+        rebuilt warning must carry the same (name, width) pairs."""
+        w = HostFallbackWarning.for_arrays((("w", 40), ("kv/k_scales", 64)))
+        rebuilt = type(w)(*w.args)
+        assert rebuilt.arrays == (("w", 40), ("kv/k_scales", 64))
+        assert str(rebuilt) == str(w)
 
     def test_fallback_warns_once_per_layout_and_array(self):
         """Serving loops decode the same layout thousands of times; the
@@ -190,16 +197,16 @@ class TestHostFallback:
         lay = schedule(p)
         buf = pack_compiled(lay, random_codes(p, seed=0))
         with pytest.warns(HostFallbackWarning):
-            decode_layout_fused(lay, buf, interpret=True)
+            decode_layout_fused(lay, buf)
         # further decodes of the same layout: silent
         with warnings.catch_warnings():
             warnings.simplefilter("error", HostFallbackWarning)
-            decode_layout_fused(lay, buf, interpret=True)
-            decode_layout_fused(lay, buf, interpret=True)
+            decode_layout_fused(lay, buf)
+            decode_layout_fused(lay, buf)
         # reset re-arms the warning for the same layout
         reset_host_fallback_warnings()
         with pytest.warns(HostFallbackWarning) as rec:
-            decode_layout_fused(lay, buf, interpret=True)
+            decode_layout_fused(lay, buf)
         assert ("w", 40) in rec[0].message.arrays
 
     def test_stream_direct_serves_wide_units_natively(self):
@@ -223,9 +230,7 @@ class TestHostFallback:
         tabs = stream_matmul_tables(lay, "w", (k, n), scales="s",
                                     group_size=g, program=prog)
         x = _x(4, k, seed=9)
-        got = np.asarray(stream_matmul(
-            x, stream_words(prog, buf), tabs.w_tab, tabs.s_tab, bits=20,
-            group_size=g, interpret=True))
+        got = np.asarray(stream_matmul(x, stream_words(prog, buf), tabs))
         want = stream_matmul_ref(
             np.asarray(x), np.asarray(stream_words(prog, buf)),
             tabs.w_tab, tabs.s_tab, bits=20, group_size=g)
@@ -277,5 +282,4 @@ class TestValidation:
         _, _, _, prog, buf, tabs = case
         sw = stream_words(prog, buf)
         with pytest.raises(ValueError, match="uint32"):
-            stream_matmul(_x(2, 64), sw.astype(jnp.int32), tabs.w_tab,
-                          tabs.s_tab, bits=4, group_size=32, interpret=True)
+            stream_matmul(_x(2, 64), sw.astype(jnp.int32), tabs)

@@ -25,9 +25,7 @@ def _run_case(case, x):
 
     _, _, _, prog, buf, tabs = case
     sw = stream_words(prog, buf)
-    got = stream_matmul(jnp.asarray(x), sw, tabs.w_tab, tabs.s_tab,
-                        bits=tabs.bits, group_size=tabs.group_size,
-                        interpret=True)
+    got = stream_matmul(jnp.asarray(x), sw, tabs)
     return np.asarray(got), np.asarray(sw), tabs
 
 
