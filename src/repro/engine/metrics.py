@@ -96,8 +96,9 @@ class EngineMetrics:
         self.steps = 0
         self.active_row_steps = 0        # sum over steps of active slots
         self.tokens_generated = 0
-        self.stream_bytes = 0            # host->device stream upload bytes
-        self.uploader_stats: dict = {}   # latest StreamUploader.stats()
+        #: StreamUploader counters summed over this engine's steps
+        #: (uploads, bytes_uploaded, prefetch_hits, sync_fetches)
+        self.uploads: dict[str, int] = {}
         self._t0: float | None = None    # first submit (throughput window)
         self._t_last: float | None = None
 
@@ -154,13 +155,11 @@ class EngineMetrics:
         self.steps += 1
         self.active_row_steps += n_active
 
-    def record_stream_bytes(self, n: int) -> None:
-        self.stream_bytes += n
-
-    def record_uploader_stats(self, stats: dict) -> None:
-        """Latest :meth:`StreamUploader.stats` counters (cumulative on
-        the uploader side, so last-write-wins is the right merge)."""
-        self.uploader_stats = dict(stats)
+    def record_uploads(self, delta: dict[str, int]) -> None:
+        """Add one step's :meth:`StreamUploader.stats` counters (the
+        change since the previous step)."""
+        for k, n in delta.items():
+            self.uploads[k] = self.uploads.get(k, 0) + n
 
     # -- aggregation ----------------------------------------------------
     def _phase(self, attr: str) -> dict:
@@ -181,6 +180,7 @@ class EngineMetrics:
         t0 = self._t0 if self._t0 is not None else now
         elapsed = max(now - t0, 1e-9)
         batch = (self.active_row_steps / self.steps) if self.steps else 0.0
+        stream_bytes = self.uploads.get("bytes_uploaded", 0)
         return {
             "requests": {
                 "submitted": self.submitted,
@@ -205,9 +205,9 @@ class EngineMetrics:
                     t.n_tokens for t in self.timings.values()
                     if t.completed is not None) / elapsed,
                 "mean_batch_occupancy": batch,
-                "stream_bytes": self.stream_bytes,
-                "stream_bytes_per_s": self.stream_bytes / elapsed,
-                "uploader": dict(self.uploader_stats),
+                "stream_bytes": stream_bytes,
+                "stream_bytes_per_s": stream_bytes / elapsed,
+                "uploader": dict(self.uploads),
             },
         }
 

@@ -46,6 +46,7 @@ import numpy as np
 
 from .metrics import EngineMetrics
 from .queue import Admission, AdmissionQueue, EngineRequest
+from .trace import span, step_span
 
 __all__ = [
     "DenseAdapter", "Engine", "EngineConfig", "PackedAdapter",
@@ -54,6 +55,9 @@ __all__ = [
 
 #: engine stages, in execution order
 STAGES = ("admit", "prefill", "decode", "retire")
+#: the :meth:`StreamUploader.stats` counters the engine accumulates
+UPLOAD_COUNTERS = ("uploads", "bytes_uploaded", "prefetch_hits",
+                   "sync_fetches")
 
 
 def greedy_sampler(logits_row, request: EngineRequest) -> int:
@@ -155,15 +159,15 @@ class DenseAdapter:
         Returns (logits rows aligned with ``active``, new state)."""
         import jax.numpy as jnp
 
-        b = int(np.asarray(state["pos"]).shape[0])
-        toks = np.zeros(b, dtype=np.int32)
-        toks[list(active)] = tokens
-        logits, state = self._step(self.params, state, jnp.asarray(toks),
-                                   None)
-        return np.asarray(logits, np.float32)[list(active)], state
-
-    def stream_bytes_uploaded(self) -> int | None:
-        return None                      # weights are resident
+        with span("repro.adapter.step"):
+            b = int(np.asarray(state["pos"]).shape[0])
+            toks = np.zeros(b, dtype=np.int32)
+            toks[list(active)] = tokens
+            logits, state = self._step(self.params, state,
+                                       jnp.asarray(toks), None)
+            with span("repro.adapter.logits"):
+                logits = np.asarray(logits, np.float32)
+            return logits[list(active)], state
 
 
 class PackedAdapter:
@@ -230,15 +234,19 @@ class PackedAdapter:
 
         from repro.models.quantized import packed_decode_step
 
-        logits, state = packed_decode_step(
-            self.cfg, self.tree, state, jnp.asarray(tokens, jnp.int32),
-            weights=self.weights,
-            slot_ids=jnp.asarray(list(active), jnp.int32),
-            stream_source=self.uploader,
-            kv=self.kv, kv_attention=self.kv_attention)
-        return np.asarray(logits, np.float32), state
+        with span("repro.adapter.step"):
+            logits, state = packed_decode_step(
+                self.cfg, self.tree, state, jnp.asarray(tokens, jnp.int32),
+                weights=self.weights,
+                slot_ids=jnp.asarray(list(active), jnp.int32),
+                stream_source=self.uploader,
+                kv=self.kv, kv_attention=self.kv_attention)
+            with span("repro.adapter.logits"):
+                return np.asarray(logits, np.float32), state
 
     def stream_bytes_uploaded(self) -> int | None:
+        """The uploader's lifetime byte count (the engine counts its
+        own uploads through :meth:`uploader_stats`)."""
         return self.uploader.bytes_uploaded if self.uploader else None
 
     def uploader_stats(self) -> dict | None:
@@ -291,7 +299,9 @@ class Engine:
         for stage, fns in (hooks or {}).items():
             for fn in fns:
                 self.add_hook(stage, fn)
-        self._stream_bytes_seen = 0
+        #: engine steps taken (the step number of ``repro.engine.step``)
+        self.steps_taken = 0
+        self._upload_seen = self._upload_counters()
         # retire-order audit trail (slot-reuse invariants in tests)
         self.admission_order: list[int] = []
         self.completion_order: list[int] = []
@@ -377,15 +387,20 @@ class Engine:
                                                active)
         ctx["logits"] = logits
         self.metrics.record_step(len(active))
-        uploaded = self.adapter.stream_bytes_uploaded()
-        if uploaded is not None:
-            self.metrics.record_stream_bytes(
-                uploaded - self._stream_bytes_seen)
-            self._stream_bytes_seen = uploaded
+        # the uploader outlives engines: count what it did since this
+        # engine's previous decode stage (or its construction)
+        now, seen = self._upload_counters(), self._upload_seen
+        if now is not None and seen is not None:
+            self.metrics.record_uploads(
+                {k: now[k] - seen[k] for k in UPLOAD_COUNTERS})
+            self._upload_seen = now
+
+    def _upload_counters(self) -> dict | None:
+        """The adapter's uploader counters, None without an uploader."""
         stats_fn = getattr(self.adapter, "uploader_stats", None)
         stats = stats_fn() if stats_fn is not None else None
-        if stats is not None:
-            self.metrics.record_uploader_stats(stats)
+        return None if stats is None else {k: stats[k]
+                                           for k in UPLOAD_COUNTERS}
 
     def _stage_retire(self, ctx: dict) -> None:
         """Per-slot sampling, completion checks, slot release."""
@@ -417,10 +432,13 @@ class Engine:
         """Run one admit -> prefill -> decode -> retire cycle; returns
         the step context (admitted/active/tokens/retired)."""
         ctx: dict = {}
-        for stage in STAGES:
-            getattr(self, f"_stage_{stage}")(ctx)
-            for fn in self.hooks[stage]:
-                fn(self, stage, ctx)
+        with step_span(self.steps_taken):
+            for stage in STAGES:
+                with span(f"repro.engine.{stage}"):
+                    getattr(self, f"_stage_{stage}")(ctx)
+                    for fn in self.hooks[stage]:
+                        fn(self, stage, ctx)
+        self.steps_taken += 1
         return ctx
 
     def has_work(self) -> bool:

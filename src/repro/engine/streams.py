@@ -34,6 +34,8 @@ from collections import OrderedDict
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Any, Callable
 
+from .trace import span
+
 __all__ = ["BufferRing", "StreamUploader"]
 
 
@@ -122,8 +124,9 @@ class StreamUploader:
         return words
 
     def _upload(self, layer: int):
-        words = self._host_words(layer)
-        out = self._device_put(words)
+        with span("repro.stream.upload", layer=layer):
+            words = self._host_words(layer)
+            out = self._device_put(words)
         with self._lock:
             self.uploads += 1
             self.bytes_uploaded += int(words.nbytes)
@@ -153,12 +156,14 @@ class StreamUploader:
             entry = self.ring.get(key)
         if entry is None:
             self.sync_fetches += 1
-            value = self._upload(layer)
+            with span("repro.stream.wait", layer=layer):
+                value = self._upload(layer)
             with self._lock:
                 self.ring.put(key, value)
         else:
             if isinstance(entry, Future):
-                value = entry.result()
+                with span("repro.stream.wait", layer=layer):
+                    value = entry.result()
                 with self._lock:
                     # cache the resolved array (idempotent re-reads)
                     self.ring.put(key, value)

@@ -150,5 +150,6 @@ def packed_matmul_call(x: jax.Array, w_packed: jax.Array, scales: jax.Array,
         out_shape=jax.ShapeDtypeStruct((m_pad, n), out_dtype),
         scratch_shapes=scratch,
         interpret=interpret,
+        name="packed_matmul_call",
     )(x, w_packed, scales)
     return out[:m] if m_pad != m else out

@@ -241,6 +241,7 @@ def stream_matmul_call(x: jax.Array, stream_words: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m_pad, n_pad), out_dtype),
         interpret=interpret,
+        name="stream_matmul_call",
     )(w_rows_flat, s_rows_flat, x, words2d, w_ent, s_ent)
     return out[:m, :n] if (m_pad, n_pad) != (m, n) else out
 

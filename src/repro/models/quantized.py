@@ -29,6 +29,7 @@ if TYPE_CHECKING:  # pragma: no cover
     from repro.tree import PackedTree
 
 from repro.configs.base import ModelConfig
+from repro.engine.trace import span
 from repro.kernels.packed_matmul import packed_matmul
 from repro.quant.qtypes import QuantSpec
 
@@ -203,17 +204,18 @@ def packed_decode_step(cfg: ModelConfig, pp: "PackedTree", state: dict,
             "(weights='stream', or 'auto' on a kernel-view-free tree)"
         )
     spec = pp.spec
-    inv_freq = rope_freqs(cfg)
     b = tokens.shape[0]
     if slot_ids is not None and slot_ids.shape[0] != b:
         raise ValueError(
             f"slot_ids has {slot_ids.shape[0]} rows but tokens has {b}"
         )
-    rows = jnp.arange(b) if slot_ids is None else slot_ids
-    pos = state["pos"] if slot_ids is None else state["pos"][rows]
     h, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    x = jnp.take(pp.other["embed"], tokens, axis=0) \
-        * jnp.asarray(cfg.d_model ** 0.5, pp.other["embed"].dtype)
+    with span("repro.model.embed"):
+        inv_freq = rope_freqs(cfg)
+        rows = jnp.arange(b) if slot_ids is None else slot_ids
+        pos = state["pos"] if slot_ids is None else state["pos"][rows]
+        x = jnp.take(pp.other["embed"], tokens, axis=0) \
+            * jnp.asarray(cfg.d_model ** 0.5, pp.other["embed"].dtype)
 
     def mm(name, period, x2d, words=None):
         if use_stream:
@@ -227,64 +229,72 @@ def packed_decode_step(cfg: ModelConfig, pp: "PackedTree", state: dict,
     new_k, new_v = [], []
     for layer in range(np_):
         words = stream_source(layer) if stream_source is not None else None
-        hnorm = apply_norm(cfg, jax.tree.map(lambda a: a[layer],
-                                             pp.other["norm1"]), x)
-        q = mm("attn/wq", layer, hnorm, words).reshape(b, 1, h, hd)
-        kk = mm("attn/wk", layer, hnorm, words).reshape(b, 1, hkv, hd)
-        vv = mm("attn/wv", layer, hnorm, words).reshape(b, 1, hkv, hd)
-        if cfg.use_bias:
-            q = q + pp.other["attn/bq"][layer].reshape(1, 1, h, hd)
-            kk = kk + pp.other["attn/bk"][layer].reshape(1, 1, hkv, hd)
-            vv = vv + pp.other["attn/bv"][layer].reshape(1, 1, hkv, hd)
-        pos_b = pos[:, None]
-        q = attn.apply_rope(q, pos_b, inv_freq, cfg.mrope_sections)
-        kk = attn.apply_rope(kk, pos_b, inv_freq, cfg.mrope_sections)
-        if kvc is not None:
-            kvc = kvc.append(kk[:, 0], vv[:, 0], pos, rows, layer=layer)
-            att = attn.stream_decode_attention(
-                kvc, q.astype(jnp.bfloat16), pos, rows, layer=layer,
-                oracle=kv_attention == "dense")
-        else:
-            kc = k_cache[layer].at[rows, pos].set(
-                kk[:, 0].astype(k_cache.dtype))
-            vc = v_cache[layer].at[rows, pos].set(
-                vv[:, 0].astype(v_cache.dtype))
-            new_k.append(kc)
-            new_v.append(vc)
-            att = attn.decode_attention(q.astype(jnp.bfloat16), kc[rows],
-                                        vc[rows], pos)
-        y = mm("attn/wo", layer, att.reshape(b, h * hd), words)
-        if cfg.use_bias:
-            y = y + pp.other["attn/bo"][layer]
-        x = x + y.astype(x.dtype)
-        h2 = apply_norm(cfg, jax.tree.map(lambda a: a[layer],
-                                          pp.other["norm2"]), x)
-        g = mm("mlp/w_gate", layer, h2, words)
-        u = mm("mlp/w_up", layer, h2, words)
-        if cfg.use_bias:
-            g = g + pp.other["mlp/b_gate"][layer]
-            u = u + pp.other["mlp/b_up"][layer]
-        hh = activation(cfg.act, g) * u
-        y2 = mm("mlp/w_down", layer, hh, words)
-        if cfg.use_bias:
-            y2 = y2 + pp.other["mlp/b_down"][layer]
-        x = x + y2.astype(x.dtype)
+        with span("repro.layer.qkv", layer=layer):
+            hnorm = apply_norm(cfg, jax.tree.map(lambda a: a[layer],
+                                                 pp.other["norm1"]), x)
+            q = mm("attn/wq", layer, hnorm, words).reshape(b, 1, h, hd)
+            kk = mm("attn/wk", layer, hnorm, words).reshape(b, 1, hkv, hd)
+            vv = mm("attn/wv", layer, hnorm, words).reshape(b, 1, hkv, hd)
+            if cfg.use_bias:
+                q = q + pp.other["attn/bq"][layer].reshape(1, 1, h, hd)
+                kk = kk + pp.other["attn/bk"][layer].reshape(1, 1, hkv, hd)
+                vv = vv + pp.other["attn/bv"][layer].reshape(1, 1, hkv, hd)
+            pos_b = pos[:, None]
+            q = attn.apply_rope(q, pos_b, inv_freq, cfg.mrope_sections)
+            kk = attn.apply_rope(kk, pos_b, inv_freq, cfg.mrope_sections)
+        with span("repro.layer.kv_write", layer=layer):
+            if kvc is not None:
+                kvc = kvc.append(kk[:, 0], vv[:, 0], pos, rows, layer=layer)
+            else:
+                kc = k_cache[layer].at[rows, pos].set(
+                    kk[:, 0].astype(k_cache.dtype))
+                vc = v_cache[layer].at[rows, pos].set(
+                    vv[:, 0].astype(v_cache.dtype))
+                new_k.append(kc)
+                new_v.append(vc)
+        with span("repro.layer.attend", layer=layer):
+            if kvc is not None:
+                att = attn.stream_decode_attention(
+                    kvc, q.astype(jnp.bfloat16), pos, rows, layer=layer,
+                    oracle=kv_attention == "dense")
+            else:
+                att = attn.decode_attention(q.astype(jnp.bfloat16),
+                                            kc[rows], vc[rows], pos)
+            y = mm("attn/wo", layer, att.reshape(b, h * hd), words)
+            if cfg.use_bias:
+                y = y + pp.other["attn/bo"][layer]
+            x = x + y.astype(x.dtype)
+        with span("repro.layer.mlp", layer=layer):
+            h2 = apply_norm(cfg, jax.tree.map(lambda a: a[layer],
+                                              pp.other["norm2"]), x)
+            g = mm("mlp/w_gate", layer, h2, words)
+            u = mm("mlp/w_up", layer, h2, words)
+            if cfg.use_bias:
+                g = g + pp.other["mlp/b_gate"][layer]
+                u = u + pp.other["mlp/b_up"][layer]
+            hh = activation(cfg.act, g) * u
+            y2 = mm("mlp/w_down", layer, hh, words)
+            if cfg.use_bias:
+                y2 = y2 + pp.other["mlp/b_down"][layer]
+            x = x + y2.astype(x.dtype)
 
-    x = apply_norm(cfg, pp.other["final_norm"], x)
-    if cfg.tie_embeddings:
-        logits = x @ pp.other["embed"].T
-    else:
-        logits = x @ pp.other["unembed"]
-    new_state = dict(state)
-    if kvc is not None:
-        new_state["packed_kv"] = kvc
-    else:
-        new_state["k_cache"] = jnp.stack(new_k)
-        new_state["v_cache"] = jnp.stack(new_v)
-    if slot_ids is None:
-        new_state["pos"] = pos + 1
-    else:
-        new_state["pos"] = state["pos"].at[rows].add(1)
+    with span("repro.model.head"):
+        x = apply_norm(cfg, pp.other["final_norm"], x)
+        if cfg.tie_embeddings:
+            logits = x @ pp.other["embed"].T
+        else:
+            logits = x @ pp.other["unembed"]
+    with span("repro.model.state"):
+        new_state = dict(state)
+        if kvc is not None:
+            new_state["packed_kv"] = kvc
+        else:
+            new_state["k_cache"] = jnp.stack(new_k)
+            new_state["v_cache"] = jnp.stack(new_v)
+        if slot_ids is None:
+            new_state["pos"] = pos + 1
+        else:
+            new_state["pos"] = state["pos"].at[rows].add(1)
     return logits, new_state
 
 

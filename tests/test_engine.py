@@ -25,6 +25,7 @@ from repro.engine import (
     greedy_sampler,
     percentile,
 )
+from repro.engine.scheduler import UPLOAD_COUNTERS
 
 try:
     import hypothesis
@@ -60,9 +61,6 @@ class StubAdapter:
         for j, t in enumerate(np.asarray(tokens)):
             logits[j, (int(t) + 1) % self.vocab] = 1.0
         return logits, state
-
-    def stream_bytes_uploaded(self):
-        return None
 
 
 def _stub_engine(batch=2, max_seq=64, **cfg_kw) -> Engine:
@@ -497,19 +495,63 @@ def test_engine_with_uploader_bit_identical(packed_setup):
     base, _ = run(None)
     with StreamUploader(tree) as up:
         uploaded, eng = run(up)
-    assert uploaded == base
-    # stream-bytes accounting flowed into the metrics
-    assert eng.metrics.stream_bytes == up.bytes_uploaded
-    snap = eng.metrics.snapshot()
-    assert snap["throughput"]["stream_bytes"] > 0
-    # the full uploader counter dict rides in the snapshot too
-    want = up.stats()
-    got = snap["throughput"]["uploader"]
-    assert got["uploads"] == want["uploads"] > 0
-    assert got["bytes_uploaded"] == want["bytes_uploaded"] == \
-        up.bytes_uploaded
-    assert got["prefetch_hits"] == want["prefetch_hits"] > 0
-    assert got["ring_depth"] == 2
+        mid = up.stats()
+        again, eng2 = run(up)
+    end = up.stats()                    # closed: no upload in flight
+    assert uploaded == base == again
+    # upload accounting flowed into the metrics, one engine at a time:
+    # the second engine does not count the first one's uploads
+    first, second = (e.metrics.snapshot()["throughput"] for e in (eng, eng2))
+    for got in (first, second):
+        assert set(got["uploader"]) == set(UPLOAD_COUNTERS)
+        assert got["uploader"]["prefetch_hits"] > 0
+        assert got["stream_bytes"] == got["uploader"]["bytes_uploaded"]
+    assert first["uploader"]["uploads"] > 0 and first["stream_bytes"] > 0
+    for k in UPLOAD_COUNTERS:
+        assert first["uploader"][k] <= mid[k]
+        assert second["uploader"][k] <= end[k] - mid[k]
+    assert second["uploader"]["uploads"] < end["uploads"]
+
+
+class CountingUploaderAdapter(StubAdapter):
+    """A stub whose uploader has a lifetime before the engine and
+    counts 3 uploads of 100 bytes, 2 prefetch hits and 1 synchronous
+    fetch per step."""
+
+    def __init__(self) -> None:
+        super().__init__()
+        self.counters = {"uploads": 7, "bytes_uploaded": 700,
+                         "prefetch_hits": 5, "sync_fetches": 2,
+                         "ring_depth": 2, "ring_evictions": 4}
+
+    def step(self, state, tokens, active):
+        for k, n in (("uploads", 3), ("bytes_uploaded", 300),
+                     ("prefetch_hits", 2), ("sync_fetches", 1),
+                     ("ring_evictions", 3)):
+            self.counters[k] += n
+        return super().step(state, tokens, active)
+
+    def uploader_stats(self):
+        return dict(self.counters)
+
+
+def test_engine_records_each_steps_upload_counters():
+    """The engine adds the uploader's change over each decode stage:
+    the snapshot counts this engine's uploads, not the uploader's
+    lifetime, and carries the counters alone (no ring depth)."""
+    eng = Engine(CountingUploaderAdapter(),
+                 EngineConfig(batch_size=2, max_seq=64))
+    for r in _reqs(2, prompt_len=1, max_new=3):
+        eng.submit(r)
+    eng.run_until_drained()
+    steps = eng.metrics.steps
+    assert steps == 3
+    got = eng.metrics.snapshot()["throughput"]
+    assert got["uploader"] == {"uploads": 3 * steps,
+                               "bytes_uploaded": 300 * steps,
+                               "prefetch_hits": 2 * steps,
+                               "sync_fetches": steps}
+    assert got["stream_bytes"] == 300 * steps
 
 
 def test_engine_without_uploader_snapshot_has_empty_uploader_dict():
