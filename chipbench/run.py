@@ -26,6 +26,16 @@ device's busy time and a breakdown.  The numbers compared for
 ``correct`` are printed as the last lines of standard error and, last,
 in the result line, which is the last line of standard output.
 
+The result line is strict JSON (no NaN or infinity: a run that would
+print one fails instead) and keeps within ``LINE_BYTES``, 8 KiB,
+whatever the number of steps, requests or traced events: the window's
+steps are summed up in a fixed number of fields (at most ``SLOWEST``
+slowest steps and the first ``FIRST_STEPS`` step times), and the
+breakdown's lists hold ``tracing.top``'s ten entries, each name cut to
+``tracing.NAME_CHARS``.  Every list in the line holds at most 16
+entries.  The line grows only with the cell's
+metrics and checks, about 70 bytes each.
+
 Exits 1, printing no result, unless JAX finds as many TPU chips as the
 cell asks for.
 """
@@ -52,6 +62,11 @@ TRACE_STEPS = 3
 TRACE_SECONDS = 5.0
 #: warm-up steps (every slot active; the second reuses freed slots)
 WARM_STEPS = 2
+#: the result line's budget in bytes
+LINE_BYTES = 8192
+#: the window's slowest steps and first steps that the result line lists
+SLOWEST = 8
+FIRST_STEPS = 16
 
 
 class NoChip(RuntimeError):
@@ -278,21 +293,34 @@ def run_cell(workload: str, seed: int, seconds: float,
 
 
 def window_summary(run: Run) -> dict:
-    """How the window's steps went: their number and host times (each
-    step's in order), the slowest step, and the compilations and
-    garbage-collection pauses inside the window."""
+    """How the window's steps went, in fixed size whatever their number:
+    the host times' median, p90, p99 and maximum; the slowest step and
+    its decode call; ``slow_steps``, the steps longer than twice the
+    median; the ``SLOWEST`` slowest steps as ``[index, step_ms,
+    decode_ms]``, slowest first; the first ``FIRST_STEPS`` step times
+    (the prefill ramp); and the compilations and garbage-collection
+    pauses inside the window."""
+    import heapq
     import statistics
 
+    from stats import percentile
+
     ms = [1e3 * (s.end - s.start) for s in run.steps]
-    slowest = max(range(len(ms)), key=ms.__getitem__)
-    return {"steps": len(ms), "step_ms_median": statistics.median(ms),
-            "step_ms_max": ms[slowest], "slowest_step": slowest,
-            "decode_ms_of_slowest": 1e3 * run.steps[slowest].decode_s,
+    median = statistics.median(ms)
+    slowest = heapq.nlargest(SLOWEST, range(len(ms)), key=ms.__getitem__)
+    return {"steps": len(ms), "step_ms_median": median,
+            "step_ms_p90": percentile(ms, 90),
+            "step_ms_p99": percentile(ms, 99),
+            "step_ms_max": ms[slowest[0]], "slowest_step": slowest[0],
+            "decode_ms_of_slowest": 1e3 * run.steps[slowest[0]].decode_s,
+            "slow_steps": sum(m > 2 * median for m in ms),
+            "slowest_steps": [[i, ms[i], 1e3 * run.steps[i].decode_s]
+                              for i in slowest],
+            "first_step_ms": ms[:FIRST_STEPS],
             "compiles": run.compiles,
             "gc_pauses": len(run.gc_pauses),
             "gc_ms_max": 1e3 * max((d for _, d in run.gc_pauses), default=0.0),
-            "gc_ms_total": 1e3 * sum(d for _, d in run.gc_pauses),
-            "step_ms": ms}
+            "gc_ms_total": 1e3 * sum(d for _, d in run.gc_pauses)}
 
 
 def main(argv=None) -> int:
@@ -308,13 +336,14 @@ def main(argv=None) -> int:
     except NoChip as e:
         print(f"chipbench: {e}", file=sys.stderr)
         return 1
-    print(f"window: {json.dumps(result['window'])}", file=sys.stderr)
+    print(f"window: {json.dumps(result['window'], allow_nan=False)}",
+          file=sys.stderr)
     for name, c in result["checks"].items():
         print(f"check {name}: {c['value']} (limit {c['limit']})",
               file=sys.stderr)
     print(f"correct: {result['correct']}", file=sys.stderr)
     sys.stderr.flush()
-    print(json.dumps(result))
+    print(json.dumps(result, allow_nan=False))  # a NaN raises: no line
     return 0
 
 

@@ -10,6 +10,8 @@ reports nothing.
 """
 from __future__ import annotations
 
+import bisect
+
 from tracing import _union
 
 STEP = "repro.engine.step"
@@ -46,7 +48,10 @@ def launches(run) -> float | None:
     host = _host(run)
     if host is None:
         return None
+    # one thread's adapter spans follow one another: the one that can
+    # hold a start is the last that begins at or before it
     adapter = sorted((s, s + d) for n, s, d in host if n == ADAPTER)
+    starts = [a for a, _b in adapter]
     count, launch_end = 0, float("-inf")
     for name, s, d in sorted(host, key=lambda e: (e[1], -e[2])):
         if name.startswith(LAUNCH):
@@ -55,7 +60,8 @@ def launches(run) -> float | None:
             launch_end = s + d
         elif not name.startswith(PUT):
             continue
-        if any(a <= s < b for a, b in adapter):
+        i = bisect.bisect_right(starts, s) - 1
+        if i >= 0 and s < adapter[i][1]:
             count += 1
     return count / sum(1 for e in host if e[0] == STEP)
 

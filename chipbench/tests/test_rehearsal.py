@@ -3,6 +3,7 @@ Pallas in interpret mode, the profiler's CPU planes standing in for the
 device's.  Also the faults the check has to catch, planted under a
 sound run: a token altered where it is produced, and a step that
 returns its state unchanged."""
+import json
 import re
 
 import numpy as np
@@ -12,6 +13,7 @@ import program
 import run
 import spec
 import tracing
+from test_result_line import check_line
 
 TINY = {"hidden_size": 64, "intermediate_size": 128,
         "num_attention_heads": 2, "num_key_value_heads": 1,
@@ -70,6 +72,7 @@ def test_cell_runs_and_is_correct(cell):
     assert all(m["value"] > 0 for m in r["metrics"].values())
     assert r["attempted"] >= MIX["clients"] and r["failed"] == 0
     assert list(r)[-1] == "checks"
+    check_line(json.dumps(r, allow_nan=False), traced=False)
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -83,6 +86,7 @@ def test_traced_cell_reports_its_layer_metrics(cell, cpu_planes):
     assert 0 < r["device"]["busy_s"] <= r["device"]["window_s"]
     assert r["metrics"]["window_compiles"]["value"] == 0
     assert len(r["breakdown"]["device_ops"]) <= 10
+    check_line(json.dumps(r, allow_nan=False), traced=True)
 
 
 def _faulty(monkeypatch, fault):
@@ -105,7 +109,6 @@ def _faulty(monkeypatch, fault):
                 "step": staticmethod(unchanged),
                 "init_state": adapter.init_state,
                 "reset_slot": adapter.reset_slot,
-                "stream_bytes_uploaded": adapter.stream_bytes_uploaded,
                 "uploader": getattr(adapter, "uploader", None)})()
         return eng
 

@@ -21,6 +21,8 @@ DEVICE_LINE = re.compile(r"^XLA Ops$")
 MODULE_LINE = re.compile(r"^XLA Modules$")
 HOST_PLANE = "/host:CPU"
 STEP = "bench.step"
+#: the longest operation or span name that :func:`top` gives
+NAME_CHARS = 100
 
 
 def xplane_file(log_dir: str) -> str:
@@ -152,4 +154,7 @@ def reduce(trace: dict, kernels: dict[str, str] | None = None) -> dict:
 
 
 def top(d: dict[str, float], n: int = 10) -> list[list]:
-    return [[k, v] for k, v in sorted(d.items(), key=lambda kv: -kv[1])[:n]]
+    """The ``n`` largest entries of ``d`` as ``[name, value]``, largest
+    first, each name cut to ``NAME_CHARS``."""
+    return [[k[:NAME_CHARS], v]
+            for k, v in sorted(d.items(), key=lambda kv: -kv[1])[:n]]
